@@ -152,8 +152,9 @@ def bench(arch: str, *, short_lens, long_len: int, gen: int, gen_long: int,
     import jax
     if jax.device_count() >= 8:
         from repro.models.model import build_model
+        from repro.launch.mesh import make_mesh
         from repro.parallel.ctx import ParallelContext
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ring_ctx = dataclasses.replace(ParallelContext(mesh=mesh),
                                        ring_prefill_min=chunk)
         ring_model = build_model(cfg, ring_ctx,

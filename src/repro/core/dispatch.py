@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import os
+import pathlib
 import queue as queue_mod
 import re
 import threading
@@ -43,19 +45,40 @@ def content_hash(fn: Callable, args_spec: Any, options: str = "") -> str:
     """Double SHA-256 over the traced program + shapes + options — the
     paper's cacheURLIdentifier/anehash scheme (§5.6): identical structure and
     options resolve to the same key; changing any shape, op, device mask, or
-    option changes it."""
-    try:
-        jaxpr = jax.make_jaxpr(fn)(*args_spec)
-        # custom_vjp params print closure objects with their memory address;
-        # scrub addresses so structurally identical traces hash identically
-        body = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
-    except Exception:  # fall back to function identity + specs
-        body = f"{getattr(fn, '__name__', repr(fn))}"
+    option changes it. A program that cannot be traced raises: keying it by
+    name instead would let two different programs share one entry."""
+    jaxpr = jax.make_jaxpr(fn)(*args_spec)
+    # custom_vjp params print closure objects with their memory address;
+    # scrub addresses so structurally identical traces hash identically
+    body = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
     spec_txt = str(jax.tree.map(
         lambda x: (getattr(x, "shape", None), str(getattr(x, "dtype", None))),
         args_spec))
     inner = hashlib.sha256((body + spec_txt + options).encode()).digest()
     return hashlib.sha256(inner).hexdigest()
+
+
+#: where compiled programs persist when nothing else says: a fixed path in
+#: the checkout (listed in .gitignore), since the path is part of the cache
+#: key and a directory that moves never hits
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_persistent_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache on a TPU, before the first
+    compile, so a later process (or the second half of this one) loads what
+    an earlier one compiled. `JAX_COMPILATION_CACHE_DIR`, when set, is JAX's
+    own and wins: no directory is set in code then. Other backends keep no
+    cache: they run the tests, whose interpret-mode programs are cheap to
+    compile, and XLA:CPU warns on every entry it loads. Returns the
+    directory in use, or None."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() != "tpu":
+        return None
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 @dataclasses.dataclass
@@ -67,7 +90,8 @@ class CacheStats:
 
 class ProgramCache:
     """Content-addressed compiled-program cache (one per process, like the
-    daemon's on-disk e5bundlecache; ours is in-memory, keyed the same way)."""
+    daemon's e5bundlecache; ours is in-memory, keyed the same way, and sits
+    over JAX's on-disk cache — `use_persistent_compile_cache`)."""
 
     def __init__(self) -> None:
         self._programs: dict[str, Any] = {}

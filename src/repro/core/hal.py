@@ -278,6 +278,25 @@ def get_target(name: str) -> Target:
     return TARGETS[name]
 
 
+# `jax.Device.device_kind` -> the target that chip is. JAX reports a v5e as
+# "TPU v5 lite" and a v5p as "TPU v5"; the marketing names are accepted too.
+DEVICE_KIND_TARGETS: Mapping[str, Target] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5e": TPU_V5E,
+    "TPU v5": TPU_V5P,
+    "TPU v5p": TPU_V5P,
+}
+
+
+def target_for_device_kind(kind: str) -> Target:
+    """The HAL target of a chip, by the `device_kind` JAX reports. A kind
+    the table does not hold is an error, never a default."""
+    if kind not in DEVICE_KIND_TARGETS:
+        raise KeyError(f"no HAL target for device kind {kind!r}; "
+                       f"have {sorted(DEVICE_KIND_TARGETS)}")
+    return DEVICE_KIND_TARGETS[kind]
+
+
 # ----------------------------------------------------------------------------
 # ANE numeric constants (paper:T3.3) — shared by numerics oracle and kernels.
 # ----------------------------------------------------------------------------

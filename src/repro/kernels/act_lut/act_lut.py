@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv, interpret_mode, pad_to, select_from_table
+from repro.kernels import compat
+from repro.kernels.common import (cdiv, pad_to, round_to_fp16,
+                                  select_from_table)
 
 
 def lut_eval(x, xs_ref, sl_ref, ic_ref, cl_ref, *, ane_mode: bool):
@@ -44,7 +46,7 @@ def lut_eval(x, xs_ref, sl_ref, ic_ref, cl_ref, *, ane_mode: bool):
     y = jnp.where(x < xs_ref[0, 0], lo_clamp, y)
     y = jnp.where(x > xs_ref[0, 32], hi_clamp, y)
     if ane_mode:
-        y = y.astype(jnp.float16).astype(jnp.float32)  # fp16 output port
+        y = round_to_fp16(y)                          # fp16 output port
     return y
 
 
@@ -88,7 +90,7 @@ def act_lut(
         ],
         out_specs=pl.BlockSpec((brows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
     )(x2, xs.reshape(1, 33).astype(jnp.float32),
       slopes.reshape(1, 32).astype(jnp.float32),
       icepts.reshape(1, 32).astype(jnp.float32),

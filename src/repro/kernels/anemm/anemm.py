@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hal
 from repro.kernels import compat
 from repro.kernels.act_lut.act_lut import lut_eval
-from repro.kernels.common import cdiv, interpret_mode, pad_to, pick_block
+from repro.kernels.common import cdiv, pad_to, pick_block
 
 
 def _kernel(a_ref, b_ref, scale_ref, bias_ref, lut_refs, o_ref, acc_ref, *,
@@ -131,7 +131,7 @@ def anemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(*operands)

@@ -15,11 +15,8 @@ the ANE and on the TPU alike.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-
-from repro.kernels.compat import interpret_mode  # noqa: F401 — re-exported;
-# kernels historically import interpret_mode from here, and the probe now
-# lives with the rest of the version-adaptive surface in compat.py.
 
 
 def pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -84,3 +81,31 @@ def select_from_table(idx: jnp.ndarray, values) -> jnp.ndarray:
         level = nxt
         bit += 1
     return level[0]
+
+
+def round_to_fp16(x: jnp.ndarray) -> jnp.ndarray:
+    """``x.astype(float16).astype(float32)`` for an fp32 tile, bit for bit,
+    in int32 arithmetic only: Mosaic on v5e cannot pack fp32 into fp16
+    (``tpu.pack_subelements``). Normal results round the mantissa to 10 bits
+    half-to-even; results below 2^-14 round half-to-even onto the 2^-24
+    subnormal grid; past 65504 they overflow to inf; inf and NaN pass
+    through; the sign rides along."""
+    i32 = jnp.int32
+    bits = jax.lax.bitcast_convert_type(x, i32)
+    sign = bits & i32(-(2 ** 31))
+    mag = bits & i32(0x7FFFFFFF)
+    lsb = (mag >> 13) & 1
+    normal = (mag + i32(0x0FFF) + lsb) & i32(~0x1FFF)
+    # subnormal: significand (implicit bit set) in units of 2^-24
+    shift = jnp.clip(i32(126) - (mag >> 23), 0, 30)
+    mant = (mag & i32(0x7FFFFF)) | i32(0x800000)
+    q = mant >> shift
+    rem = mant & ((i32(1) << shift) - 1)
+    half = i32(1) << jnp.maximum(shift - 1, 0)
+    q = q + ((rem > half) | ((rem == half) & ((q & 1) == 1))).astype(i32)
+    sub = jax.lax.bitcast_convert_type(
+        q.astype(jnp.float32) * jnp.float32(2.0 ** -24), i32)
+    out = jnp.where(mag < i32(0x38800000), sub, normal)
+    out = jnp.where(out > i32(0x477FE000), i32(0x7F800000), out)
+    out = jnp.where(mag >= i32(0x7F800000), mag, out)
+    return jax.lax.bitcast_convert_type(out | sign, jnp.float32)

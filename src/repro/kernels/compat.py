@@ -1,134 +1,57 @@
-"""Version-adaptive JAX/Pallas compatibility surface.
+"""The JAX/Pallas surface the kernels and the model stack share.
 
-The platform target is a moving API: the Pallas TPU compiler-params class
-was renamed (``TPUCompilerParams`` on jax 0.4.x -> ``CompilerParams`` on
-0.5+), the path-aware pytree helpers migrated from ``jax.tree_util`` onto
-``jax.tree``, and the set of accepted compiler-param fields drifts between
-releases. Mirroring the paper's capability discipline (§4: a capability is
-what compiles and runs, not what a table attests), this module probes the
-*installed* JAX once at import time and exposes one stable surface:
+Written against one installation: jax/jaxlib 0.9.0 with libtpu 0.0.34.
+Every name here is a thin alias of the 0.9 API, kept so call sites name one
+module and nothing else in the tree names a Pallas TPU params class
+(enforced by the conformance suite):
 
-    compiler_params(dimension_semantics=..., ...)  -> params pallas_call takes
-    pallas_call_params(...)                        -> kwargs dict (or {} when
-                                                      no params class exists)
-    tree_flatten_with_path / tree_map_with_path    -> path-aware pytree ops
+    compiler_params(dimension_semantics=..., ...)  -> pltpu.CompilerParams
+    pallas_call_params(...)                        -> {"compiler_params": ...}
+    prefetch_grid_spec(...)                        -> pltpu.PrefetchScalarGridSpec
+    tree_flatten_with_path / tree_map_with_path    -> jax.tree.*_with_path
+    axis_size(name)                                -> jax.lax.axis_size
     interpret_mode()                               -> True off-TPU
 
-Every kernel family routes through this layer; nothing else in the tree may
-name the versioned classes directly (enforced by the conformance suite).
+Nothing here falls back: a field the params class does not know raises, and
+a backend that cannot be probed raises instead of quietly interpreting.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
-import re
-from typing import Any, Callable
+from typing import Any
 
 import jax
-
-try:  # pallas is present in every supported jax, but stay import-safe
-    from jax.experimental.pallas import tpu as _pltpu
-except Exception:  # pragma: no cover - exotic builds without pallas
-    _pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-# ---------------------------------------------------------------------------
-# Version probing
-# ---------------------------------------------------------------------------
-
-
-@functools.cache
-def jax_version() -> tuple[int, int, int]:
-    """The installed jax version as a comparable (major, minor, patch)."""
-    parts = re.findall(r"\d+", jax.__version__)[:3]
-    parts += ["0"] * (3 - len(parts))
-    return tuple(int(p) for p in parts)  # type: ignore[return-value]
-
-
-@functools.cache
-def _compiler_params_cls() -> type | None:
-    """The Pallas TPU compiler-params class under whichever name this jax
-    ships it. Resolution is structural (probe both names), never a version
-    pin — a backport or rename lands here automatically."""
-    if _pltpu is None:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(_pltpu, name, None)
-        if cls is not None:
-            return cls
-    return None
-
-
-@functools.cache
-def compiler_param_fields() -> frozenset[str]:
-    """Field names the installed compiler-params class accepts."""
-    cls = _compiler_params_cls()
-    if cls is None:
-        return frozenset()
-    if dataclasses.is_dataclass(cls):
-        return frozenset(f.name for f in dataclasses.fields(cls))
-    import inspect
-
-    try:
-        return frozenset(inspect.signature(cls).parameters)
-    except (TypeError, ValueError):  # pragma: no cover
-        return frozenset()
-
-
-# ---------------------------------------------------------------------------
-# compiler_params surface
-# ---------------------------------------------------------------------------
-
-
-def compiler_params(**kwargs: Any):
-    """Build the TPU compiler-params object for this jax, dropping any field
-    the installed class does not know (a field that vanished in a rename is a
-    hint we can live without, not an error)."""
-    cls = _compiler_params_cls()
-    if cls is None:
-        return None
-    accepted = compiler_param_fields()
-    kept = {k: v for k, v in kwargs.items() if k in accepted and v is not None}
-    return cls(**kept)
+def compiler_params(**kwargs: Any) -> pltpu.CompilerParams:
+    """The Pallas TPU compiler params; an unknown field is a TypeError."""
+    return pltpu.CompilerParams(**kwargs)
 
 
 def pallas_call_params(**kwargs: Any) -> dict[str, Any]:
-    """``compiler_params=...`` kwargs for ``pl.pallas_call``, or ``{}`` when
-    the installed Pallas exposes no params class (interpret-only builds)."""
-    params = compiler_params(**kwargs)
-    if params is None:
-        return {}
-    return {"compiler_params": params}
+    """``compiler_params=...`` kwargs for ``pl.pallas_call``."""
+    return {"compiler_params": compiler_params(**kwargs)}
 
 
-# ---------------------------------------------------------------------------
-# Path-aware pytree helpers (jax.tree.* on 0.5+, jax.tree_util on 0.4.x)
-# ---------------------------------------------------------------------------
+def prefetch_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
+                       out_specs, scratch_shapes=()) -> pltpu.PrefetchScalarGridSpec:
+    """Scalar-prefetch grid spec: the prefetched operands land in SMEM before
+    the body runs, and every BlockSpec index map receives them as trailing
+    arguments — how the paged decode path resolves its arena blocks."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch, grid=grid,
+        in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch_shapes)
 
 
-def _tree_fn(modern_name: str, legacy_name: str) -> Callable:
-    tree_mod = getattr(jax, "tree", None)
-    fn = getattr(tree_mod, modern_name, None) if tree_mod is not None else None
-    if fn is None:
-        fn = getattr(jax.tree_util, legacy_name)
-    return fn
-
-
-def tree_flatten_with_path(tree: Any, is_leaf: Callable | None = None):
-    """(path, leaf) pairs + treedef, under whichever module ships it."""
-    return _tree_fn("flatten_with_path", "tree_flatten_with_path")(
-        tree, is_leaf=is_leaf)
-
-
-def tree_map_with_path(f: Callable, tree: Any, *rest: Any,
-                       is_leaf: Callable | None = None):
-    return _tree_fn("map_with_path", "tree_map_with_path")(
-        f, tree, *rest, is_leaf=is_leaf)
+tree_flatten_with_path = jax.tree.flatten_with_path
+tree_map_with_path = jax.tree.map_with_path
+axis_size = jax.lax.axis_size
 
 
 def tree_path_str(path: Any) -> str:
-    """A stable ``a/b/0/c`` rendering of a key path across jax versions."""
+    """A stable ``a/b/0/c`` rendering of a key path."""
     parts = []
     for entry in path:
         for attr in ("key", "idx", "name"):
@@ -140,54 +63,10 @@ def tree_path_str(path: Any) -> str:
     return "/".join(parts)
 
 
-# ---------------------------------------------------------------------------
-# Scalar-prefetch grid specs
-# ---------------------------------------------------------------------------
-
-
-def prefetch_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
-                       out_specs, scratch_shapes=()):
-    """``pltpu.PrefetchScalarGridSpec`` under whichever Pallas ships it, or
-    ``None`` when the installed build has no scalar prefetch (callers fall
-    back to a gather-outside-the-kernel path). Scalar-prefetch arguments are
-    how a kernel's BlockSpec index maps read a page table before the body
-    runs — the paged-KV decode path resolves its arena blocks through this."""
-    if _pltpu is None:
-        return None
-    cls = getattr(_pltpu, "PrefetchScalarGridSpec", None)
-    if cls is None:
-        return None
-    return cls(num_scalar_prefetch=num_scalar_prefetch, grid=grid,
-               in_specs=in_specs, out_specs=out_specs,
-               scratch_shapes=scratch_shapes)
-
-
-# ---------------------------------------------------------------------------
-# Named-axis helpers
-# ---------------------------------------------------------------------------
-
-
-def axis_size(name: str):
-    """Size of a named mapped axis inside shard_map/pmap. ``jax.lax.axis_size``
-    only exists on newer jax; the ``psum(1, axis)`` idiom is the portable
-    spelling (it folds to a static int for a constant operand)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return jax.lax.psum(1, name)
-
-
-# ---------------------------------------------------------------------------
-# Interpret mode
-# ---------------------------------------------------------------------------
-
-
 @functools.cache
 def interpret_mode() -> bool:
-    """Pallas ``interpret=True`` everywhere except a real TPU backend — the
-    kernel body runs in Python and the oracle sweeps validate it bit-for-bit
-    against ref.py on any host."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover - backend probing failed: stay safe
-        return True
+    """Pallas ``interpret=True`` everywhere except a TPU backend: the kernel
+    body runs as XLA ops and the oracle sweeps check it against ref.py on any
+    host. Kernels read it as ``compat.interpret_mode()`` at trace time, so
+    this module attribute is the one place a test switches Mosaic on."""
+    return jax.default_backend() != "tpu"

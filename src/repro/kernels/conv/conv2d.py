@@ -31,7 +31,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hal
 from repro.kernels import compat
 from repro.kernels.act_lut.act_lut import lut_eval
-from repro.kernels.common import interpret_mode, pad_to
+from repro.kernels.common import pad_to
 
 
 def out_extent(size: int, k: int, stride: int, padding: str) -> int:
@@ -157,7 +157,7 @@ def conv2d(
         out_specs=pl.BlockSpec((1, oh, ow, cout_p), lambda bb: (bb, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, cout_p), out_dtype),
         scratch_shapes=[pltpu.VMEM((oh * ow, cout_p), jnp.float32)],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(dimension_semantics=("parallel",)),
     )(*operands)
     return out[..., :cout]

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import compat
-from repro.kernels.common import interpret_mode, pad_to
+from repro.kernels.common import pad_to
 from repro.kernels.conv.conv2d import out_extent, pad_explicit
 
 
@@ -63,7 +63,7 @@ def _pool(x, *, window, stride, padding, kind):
         in_specs=[pl.BlockSpec((1, hp, wp, cp), lambda bb: (bb, 0, 0, 0))],
         out_specs=pl.BlockSpec((1, oh, ow, cp), lambda bb: (bb, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, oh, ow, cp), x.dtype),
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(dimension_semantics=("parallel",)),
     )(xp)
     return out[..., :c]

@@ -5,10 +5,15 @@ against a 32k-500k entry cache. Decode attention is pure weight/cache
 streaming — arithmetic intensity ~1 — so the kernel's job is to keep HBM
 reads perfectly sequential and the softmax state in VMEM:
 
-grid (B, KVH, S/bk), KV innermost; scratch carries the online-softmax
-(m, l, acc) for the g grouped query heads of one kv head. Invalid cache
-slots (beyond the written length, or outside a rolling window) mask via the
-positions array, which streams alongside the cache.
+grid (B, S/bk), KV innermost; one step loads a (bk, KV*d) slab of the cache
+— every kv head of bk slots, contiguous rows — and walks the kv heads with
+static lane slices, carrying the online-softmax (m, l, acc) of each head's
+g grouped query heads in VMEM scratch. The cache is read through its free
+(B, S, KV*d) view, so every block's last two dims are either full or
+(8, 128)-tiled, as Mosaic requires. The decode position is a scalar-
+prefetch operand (SMEM); invalid cache slots (beyond the written length, or
+outside a rolling window) mask via the positions array, which streams
+alongside the cache.
 """
 
 from __future__ import annotations
@@ -21,46 +26,72 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
-from repro.kernels.common import cdiv, interpret_mode, pad_to
+from repro.kernels.common import cdiv, pad_to
 
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, nk: int, scale: float, window,
-            out_dtype):
-    ki = pl.program_id(2)
+def _attend_block(q_ref, k_ref, v_ref, valid, m_ref, l_ref, acc_ref, *,
+                  kvh: int, d: int, scale: float):
+    """One cache block for every kv head: q_ref (1, KV, g, d), k/v_ref
+    (1, bk, KV*d), valid (1, bk). Per head: one (g, d) x (bk, d)
+    online-softmax update, shared by the monolithic and paged kernels so
+    the two stay bit-identical."""
+    for h in range(kvh):
+        q = q_ref[0, h].astype(jnp.float32)                   # (g, d)
+        k = k_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)  # (bk, d)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid, s, NEG_INF)                      # (g, bk)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+        m_ref[h] = m_new
+        v = v_ref[0, :, h * d:(h + 1) * d].astype(jnp.float32)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _store(o_ref, l_ref, acc_ref, out_dtype):
+    o_ref[0] = (acc_ref[...]
+                / jnp.maximum(l_ref[...], 1e-30)).astype(out_dtype)
+
+
+def _scratch(kvh: int, g: int, d: int):
+    return [pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, 1), jnp.float32),
+            pltpu.VMEM((kvh, g, d), jnp.float32)]
+
+
+def _kernel(cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+            m_ref, l_ref, acc_ref, *, nk: int, kvh: int, d: int,
+            scale: float, window, out_dtype):
+    bi = pl.program_id(0)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (g, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (bk, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = pos_ref[0]                                  # (bk,) written positions
-    cur = cur_ref[0, 0]
+    pos = pos_ref[0]                                  # (1, bk) written positions
+    cur = cur_ref[bi]
     valid = (pos >= 0) & (pos <= cur)
     if window is not None:
         valid &= (cur - pos) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)         # (g, bk)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-    m_ref[...] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (bk, d)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    _attend_block(q_ref, k_ref, v_ref, valid, m_ref, l_ref, acc_ref,
+                  kvh=kvh, d=d, scale=scale)
 
     @pl.when(ki == nk - 1)
-    def _store():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(out_dtype)
+    def _done():
+        _store(o_ref, l_ref, acc_ref, out_dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bk", "scale"))
@@ -80,41 +111,38 @@ def decode_attention(
     g = h // kvh
     scale = scale if scale is not None else d ** -0.5
     bk = min(bk, max(s, 8))
-    kp = pad_to(k_cache, 1, bk)
-    vp = pad_to(v_cache, 1, bk)
-    pp = pad_to(positions, 1, bk)
-    if pp.shape[1] != positions.shape[1]:
-        # padded slots must read as empty
-        pad_width = pp.shape[1] - positions.shape[1]
-        pp = jnp.concatenate([positions,
-                              jnp.full((b, pad_width), -1, positions.dtype)],
-                             axis=1)
+    # (B, S, KV*d): a free view; one block row is every kv head of one slot
+    kp = pad_to(k_cache.reshape(b, s, kvh * d), 1, bk)
+    vp = pad_to(v_cache.reshape(b, s, kvh * d), 1, bk)
+    # padded slots must read as empty
+    pp = jnp.pad(positions, ((0, 0), (0, kp.shape[1] - s)),
+                 constant_values=-1).reshape(b, 1, -1)
     nk = cdiv(kp.shape[1], bk)
     # (B, KVH, g, d) query layout: kv-head-major groups
     qg = q.reshape(b, kvh, g, d)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, nk=nk, scale=scale, window=window,
-                          out_dtype=q.dtype),
-        grid=(b, kvh, nk),
+    grid_spec = compat.prefetch_grid_spec(
+        num_scalar_prefetch=1,
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk, 1, d), lambda bi, hi, ki: (bi, ki, hi, 0)),
-            pl.BlockSpec((1, bk), lambda bi, hi, ki: (bi, ki)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ki: (bi, 0)),
+            pl.BlockSpec((1, kvh, g, d), lambda bi, ki, c: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, bk, kvh * d), lambda bi, ki, c: (bi, ki, 0)),
+            pl.BlockSpec((1, bk, kvh * d), lambda bi, ki, c: (bi, ki, 0)),
+            pl.BlockSpec((1, 1, bk), lambda bi, ki, c: (bi, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, g, d),
+                               lambda bi, ki, c: (bi, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, d),
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, nk=nk, kvh=kvh, d=d, scale=scale,
+                          window=window, out_dtype=q.dtype),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(qg, kp, vp, pp, current.reshape(b, 1).astype(jnp.int32))
+            dimension_semantics=("parallel", "arbitrary")),
+    )(current.astype(jnp.int32), qg, kp, vp, pp)
     return out.reshape(b, h, d)
 
 
@@ -146,43 +174,28 @@ def decode_attention_ref(q, k_cache, v_cache, positions, current,
 # ---------------------------------------------------------------------------
 
 
-def _paged_kernel(bt_ref, q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, nb: int, scale: float, window,
-                  out_dtype):
+def _paged_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, nb: int, kvh: int, d: int,
+                  scale: float, window, out_dtype):
     bi = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)              # (g, d)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (bs, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = pos_ref[0]                                  # (bs,) written positions
-    cur = cur_ref[0, 0]
+    pos = pos_ref[0]                                  # (1, bs) written positions
+    cur = cur_ref[bi]
     mapped = bt_ref[bi, ki] >= 0                      # -1 = unmapped page
     valid = mapped & (pos >= 0) & (pos <= cur)
     if window is not None:
         valid &= (cur - pos) < window
-    s = jnp.where(valid[None, :], s, NEG_INF)         # (g, bs)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
-    m_ref[...] = m_new
-    v = v_ref[0, :, 0].astype(jnp.float32)            # (bs, d)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    _attend_block(q_ref, k_ref, v_ref, valid, m_ref, l_ref, acc_ref,
+                  kvh=kvh, d=d, scale=scale)
 
     @pl.when(ki == nb - 1)
-    def _store():
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l_ref[...], 1e-30)).astype(out_dtype)
+    def _done():
+        _store(o_ref, l_ref, acc_ref, out_dtype)
 
 
 def gather_pages(arena: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
@@ -215,51 +228,34 @@ def paged_decode_attention(
     scale = scale if scale is not None else d ** -0.5
     qg = q.reshape(b, kvh, g, d)
     bt = block_tables.astype(jnp.int32)
-    cur = current.reshape(b, 1).astype(jnp.int32)
+
+    def page(bi, ki, t, c):                 # arena block of lane page ki
+        return jnp.maximum(t[bi, ki], 0), 0, 0
 
     grid_spec = compat.prefetch_grid_spec(
-        num_scalar_prefetch=1,
-        grid=(b, kvh, nb),
+        num_scalar_prefetch=2,
+        grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, hi, ki, t: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda bi, hi, ki, t: (jnp.maximum(t[bi, ki], 0),
-                                                0, hi, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda bi, hi, ki, t: (jnp.maximum(t[bi, ki], 0),
-                                                0, hi, 0)),
-            pl.BlockSpec((1, bs),
-                         lambda bi, hi, ki, t: (jnp.maximum(t[bi, ki], 0), 0)),
-            pl.BlockSpec((1, 1), lambda bi, hi, ki, t: (bi, 0)),
+            pl.BlockSpec((1, kvh, g, d), lambda bi, ki, t, c: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, bs, kvh * d), page),
+            pl.BlockSpec((1, bs, kvh * d), page),
+            pl.BlockSpec((1, 1, bs), page),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, hi, ki, t: (bi, hi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, g, d),
+                               lambda bi, ki, t, c: (bi, 0, 0, 0)),
+        scratch_shapes=_scratch(kvh, g, d),
     )
-    if grid_spec is None:       # no scalar prefetch in this Pallas: gather
-        # the pages outside the kernel and run the monolithic-slab path with
-        # one KV chunk per page — identical accumulation order, so the two
-        # paths stay bit-identical in interpret mode
-        k_cache = gather_pages(k_arena, bt)
-        v_cache = gather_pages(v_arena, bt)
-        pos = jnp.where(
-            jnp.repeat(bt >= 0, bs, axis=1), gather_pages(pos_arena, bt), -1)
-        return decode_attention(q, k_cache, v_cache, pos, current,
-                                window=window, bk=bs, scale=scale)
-
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, nb=nb, scale=scale, window=window,
-                          out_dtype=q.dtype),
+        functools.partial(_paged_kernel, nb=nb, kvh=kvh, d=d, scale=scale,
+                          window=window, out_dtype=q.dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(bt, qg, k_arena, v_arena, pos_arena, cur)
+            dimension_semantics=("parallel", "arbitrary")),
+    )(bt, current.astype(jnp.int32), qg,
+      k_arena.reshape(n, bs, kvh * d), v_arena.reshape(n, bs, kvh * d),
+      pos_arena.reshape(n, 1, bs))
     return out.reshape(b, h, d)
 
 

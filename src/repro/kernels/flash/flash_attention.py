@@ -23,7 +23,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
-from repro.kernels.common import cdiv, interpret_mode, pad_to
+from repro.kernels.common import cdiv, pad_to
 
 NEG_INF = -1e30
 
@@ -139,7 +139,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)

@@ -27,7 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
-from repro.kernels.common import (cdiv, interpret_mode, pad_to, pick_block,
+from repro.kernels.common import (cdiv, pad_to, pick_block,
                                   select_from_table)
 
 
@@ -116,7 +116,7 @@ def palette_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(ap, wp, lut2)

@@ -24,7 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import compat
-from repro.kernels.common import cdiv, interpret_mode, pad_to, pick_block
+from repro.kernels.common import cdiv, pad_to, pick_block
 
 
 def pack_pair_sparse(w: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -113,7 +113,7 @@ def sparse_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
         **compat.pallas_call_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(ap, vp, sp)

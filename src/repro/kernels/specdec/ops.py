@@ -60,8 +60,7 @@ def verify_accept(scores: jnp.ndarray, draft: jnp.ndarray, *,
 
     return route_and_run(
         dispatcher, "specdec", scores.dtype,
-        lambda: verify_accept_kernel(scores, draft),
-        lambda: verify_accept_ref(scores, draft))
+        verify_accept_kernel, verify_accept_ref, scores, draft)
 
 
 def verify_accept_tree(scores: jnp.ndarray, draft: jnp.ndarray, *,
@@ -80,5 +79,4 @@ def verify_accept_tree(scores: jnp.ndarray, draft: jnp.ndarray, *,
 
     return route_and_run(
         dispatcher, "specdec_tree", scores.dtype,
-        lambda: verify_accept_tree_kernel(scores, draft),
-        lambda: verify_accept_tree_ref(scores, draft))
+        verify_accept_tree_kernel, verify_accept_tree_ref, scores, draft)

@@ -35,7 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import cdiv, interpret_mode
+from repro.kernels import compat
+from repro.kernels.common import cdiv
 
 NEG_INF = float("-inf")
 
@@ -138,7 +139,7 @@ def verify_accept_tree_kernel(scores: jnp.ndarray, draft: jnp.ndarray, *,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ),
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
     )(sp, dp)
     return samples, accept[:, 0], branch[:, 0]
 
@@ -183,6 +184,6 @@ def verify_accept_kernel(scores: jnp.ndarray, draft: jnp.ndarray, *,
             jax.ShapeDtypeStruct((b, t), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ),
-        interpret=interpret_mode(),
+        interpret=compat.interpret_mode(),
     )(sp, dp)
     return samples, accept[:, 0]

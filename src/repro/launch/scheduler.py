@@ -367,6 +367,22 @@ class _SchedulerBase:
             lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
             tree, specs)
 
+    def _resident_jit(self, caches, *rest) -> dict:
+        """jit kwargs for a program over the resident cache (arguments
+        `params, caches, ...`; outputs `caches, *rest`). The cache is
+        donated. On a mesh its output is pinned to its input placement and
+        each other output to the sharding given (None: replicated), so the
+        next dispatch gets the layout it was compiled for: kernels run
+        replicated on a mesh (`dispatched.use_mesh`), and unpinned their
+        outputs would pull the cache off its lane sharding."""
+        kw = {"donate_argnums": (1,)}
+        if self.ctx.active:
+            rep = self.ctx.sharding()
+            kw["out_shardings"] = (
+                jax.tree.map(lambda a: a.sharding, caches),
+                *(rep if s is None else s for s in rest))
+        return kw
+
     def _batch_put(self, x):
         """Host batch array -> device, lane dim sharded over the batch axes
         when divisible (replicated otherwise) — `batch_specs`, applied to
@@ -403,7 +419,8 @@ class _SchedulerBase:
             return hit
         compiled, key = self.cache.compile(
             self.model.decode_step, self.params, caches, tok, pos,
-            options=self._copts, jit_kwargs={"donate_argnums": (1,)})
+            options=self._copts,
+            jit_kwargs=self._resident_jit(caches, None))
         self._decode_memo[sig] = (compiled, key)
         return compiled, key
 
@@ -995,7 +1012,8 @@ class SLOSchedule(ContinuousSchedule):
 
         compiled, key = self.cache.compile(
             fused, self.params, caches, tok, pos, forced, do_sample, rids,
-            options=self._copts, jit_kwargs={"donate_argnums": (1,)})
+            options=self._copts,
+            jit_kwargs=self._resident_jit(caches, tok.sharding, None))
         self._decode_keys.add(key)
         hit = (compiled, key)
         self._step_memo[sig] = hit

@@ -77,7 +77,9 @@ import numpy as np
 from repro import configs
 from repro.core import hal
 from repro.core.dispatch import (AsyncExecutionStream, ExecutionStream,
-                                 KernelDispatcher, ProgramCache)
+                                 KernelDispatcher, ProgramCache,
+                                 use_persistent_compile_cache)
+from repro.launch.mesh import make_mesh
 from repro.launch.scheduler import (SAMPLING_MODES, SCHEDULES, ChunkConfig,
                                     PrefixConfig, Request, ServeConfig,
                                     SLOConfig, SpecConfig, build_scheduler,
@@ -113,7 +115,17 @@ def parse_mesh(spec: str) -> ParallelContext:
             f"--mesh-shape {spec} wants {need} devices, {have} visible "
             f"(XLA_FLAGS=--xla_force_host_platform_device_count={need} "
             "fakes them on CPU)")
-    return ParallelContext(mesh=jax.make_mesh(dims, names))
+    return ParallelContext(mesh=make_mesh(dims, names))
+
+
+def device_target() -> hal.Target:
+    """The HAL target of the device JAX runs on: a TPU by its `device_kind`
+    (a kind `hal.DEVICE_KIND_TARGETS` does not hold is an error), any other
+    backend as the v5e that its interpret-mode kernels emulate."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return hal.target_for_device_kind(dev.device_kind)
+    return hal.TPU_V5E
 
 
 def run(argv=None) -> dict:
@@ -199,10 +211,12 @@ def run(argv=None) -> dict:
                     help="greedy argmax or seeded categorical sampling")
     ap.add_argument("--weight-form", default="fp16", choices=WEIGHT_FORMS,
                     help="pack matmul weights into this streamed form")
-    ap.add_argument("--target", default="tpu-v5e",
+    ap.add_argument("--target", default=None,
                     choices=sorted(hal.TARGETS),
                     help="HAL target whose capability surface gates routing "
-                         "(also sets the costmodel dispatch floor)")
+                         "(also sets the costmodel dispatch floor); default: "
+                         "the chip JAX runs on, by its device kind (off the "
+                         "chip, the v5e the interpret-mode kernels emulate)")
     ap.add_argument("--no-dispatch", action="store_true",
                     help="bypass the dispatcher (seed dense path; "
                          "incompatible with a packed --weight-form)")
@@ -251,8 +265,9 @@ def run(argv=None) -> dict:
         ctx = dataclasses.replace(ctx,
                                   ring_prefill_min=args.ring_prefill_min)
 
+    use_persistent_compile_cache()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
-    target = hal.get_target(args.target)
+    target = hal.get_target(args.target) if args.target else device_target()
     dispatcher = None if args.no_dispatch else KernelDispatcher(target)
     model = build_model(cfg, ctx, dispatcher=dispatcher)
     params = model.init(jax.random.PRNGKey(args.seed))
@@ -341,15 +356,23 @@ def run(argv=None) -> dict:
     else:
         engine = make_sched(ctx, None)
 
-    results = []
+    results, rounds = [], []
     t0 = time.perf_counter()
     for r in range(max(args.requests, 1)):
         reqs = [Request(rid=r * len(lens) + i, prompt=prompts[i],
                         max_new_tokens=args.gen,
                         frames=None if frames is None else frames[i])
                 for i in range(len(lens))]
+        before = dataclasses.replace(program_cache.stats)
+        t_round = time.perf_counter()
         results = supervisor.serve(reqs) if supervisor is not None \
             else engine.run(reqs)
+        after = program_cache.stats
+        rounds.append({
+            "start_s": t_round, "end_s": time.perf_counter(),
+            "cache_hits": after.hits - before.hits,
+            "cache_misses": after.misses - before.misses,
+            "compile_s": after.compile_seconds - before.compile_seconds})
     wall = time.perf_counter() - t0
 
     n_requests = len(lens) * max(args.requests, 1)
@@ -367,6 +390,7 @@ def run(argv=None) -> dict:
         "tok_per_s": total_tokens / serve_wall,
         "cache_hits": program_cache.stats.hits,
         "cache_misses": program_cache.stats.misses,
+        "rounds": rounds,
         "results": results,
         **stats,
     }

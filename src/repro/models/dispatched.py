@@ -30,11 +30,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Callable, Iterator
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.core.dispatch import KernelDispatcher
 from repro.core.hal import WeightForm
@@ -213,6 +215,33 @@ def active_dispatcher() -> KernelDispatcher | None:
     return _SCOPE[-1] if _SCOPE else None
 
 
+_MESH: list[Any] = []
+
+
+@contextlib.contextmanager
+def use_mesh(mesh) -> Iterator[None]:
+    """Run every Pallas kernel traced inside on each device of `mesh`, in a
+    `shard_map` with replicated operands. Mosaic kernels cannot be
+    partitioned automatically, so a multi-device program must hand each one
+    a manual region; replication computes exactly what one device would,
+    which keeps mesh streams bit-identical to single-device. `None` or a
+    one-device mesh is a no-op scope; a body that is already manual (the
+    MoE EP `shard_map`) opens `use_mesh(None)`."""
+    _MESH.append(mesh if mesh is not None and mesh.devices.size > 1 else None)
+    try:
+        yield
+    finally:
+        _MESH.pop()
+
+
+def _on_every_device(fn: Callable, args: tuple):
+    mesh = _MESH[-1] if _MESH else None
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
+
+
 _FUSION: list[bool] = []
 
 
@@ -251,14 +280,18 @@ def _dispatcher_for(w: Any) -> KernelDispatcher | None:
 
 
 def route_and_run(disp: KernelDispatcher, name: str, dtype,
-                  native: Callable[[], Any], oracle: Callable[[], Any]):
+                  native: Callable[..., Any], oracle: Callable[..., Any],
+                  *args):
     """One op-by-device cell: resolve through the dispatcher's capability
-    gates, record the route, run the winning backend. Unlike
+    gates, record the route, run the winning backend on `args`. Unlike
     `KernelDispatcher.__call__` the two legs are callables, so call sites
-    can pass extra kwargs (window, causal) or differentiable wrappers."""
+    can pass extra kwargs (window, causal) or differentiable wrappers. The
+    Pallas leg runs on every device of the mesh in scope (`use_mesh`)."""
     route = disp.resolve(name, dtype)
     disp.routes.append(route)
-    return native() if route.native else oracle()
+    if route.native:
+        return _on_every_device(native, args)
+    return oracle(*args)
 
 
 def _matmul_dense(disp: KernelDispatcher, a2: jnp.ndarray,
@@ -268,14 +301,14 @@ def _matmul_dense(disp: KernelDispatcher, a2: jnp.ndarray,
 
     return route_and_run(
         disp, "anemm", a2.dtype,
-        lambda: anemm_ops.matmul(a2, w2.astype(a2.dtype)),
-        lambda: anemm_ref(a2, w2.astype(a2.dtype)))
+        lambda a, w: anemm_ops.matmul(a, w.astype(a.dtype)),
+        lambda a, w: anemm_ref(a, w.astype(a.dtype)), a2, w2)
 
 
 def _matmul_packed(disp: KernelDispatcher, a2: jnp.ndarray,
                    w: DispatchedWeight) -> jnp.ndarray:
-    # "a" first: KernelDispatcher resolves the route off the bundle's first
-    # floating leaf (the activation dtype).
+    from repro.kernels import registry
+
     if w.form == WeightForm.INT4_PALETTE:
         bundle = {"a": a2, "packed": w.payload["packed"],
                   "lut": w.payload["lut"]}
@@ -284,7 +317,9 @@ def _matmul_packed(disp: KernelDispatcher, a2: jnp.ndarray,
                   "selector": w.payload["selector"]}
     else:
         raise ValueError(f"no streaming kernel for {w.form}")
-    return disp(w.kernel, bundle)
+    spec = registry.get(w.kernel)
+    return route_and_run(disp, w.kernel, a2.dtype, spec.run_kernel,
+                         spec.run_oracle, bundle)
 
 
 def linear(x: jnp.ndarray, w: Any, *, n_contract: int = 1,
@@ -330,18 +365,18 @@ def flash_route(disp: KernelDispatcher, q: jnp.ndarray, k: jnp.ndarray,
     """Fused-attention cell for (B, S, H, dh)-layout q/k/v. Native = the
     Pallas flash kernel (recompute backward, so the train path
     differentiates); gated = the chunked online-softmax reference."""
-    def native():
+    def native(q, k, v):
         from repro.kernels.flash import ops as flash_ops
         out = flash_ops.attention(q.transpose(0, 2, 1, 3),
                                   k.transpose(0, 2, 1, 3),
                                   v.transpose(0, 2, 1, 3), causal, None)
         return out.transpose(0, 2, 1, 3)
 
-    def oracle():
+    def oracle(q, k, v):
         from repro.models.attention import chunked_attention
         return chunked_attention(q, k, v, causal=causal)
 
-    return route_and_run(disp, "flash", q.dtype, native, oracle)
+    return route_and_run(disp, "flash", q.dtype, native, oracle, q, k, v)
 
 
 def decode_route(disp: KernelDispatcher, q: jnp.ndarray,
@@ -351,17 +386,14 @@ def decode_route(disp: KernelDispatcher, q: jnp.ndarray,
     """One-token decode cell: q (B, H, dh) against a (B, S, KV, dh) cache.
     Gated on `gather` (H13/M1 has none), so the op-by-device matrix sends
     this to the oracle on early ANE targets — the paper's cell, live."""
-    def native():
-        from repro.kernels.flash.decode_attention import decode_attention
-        return decode_attention(q, k_cache, v_cache, positions, current,
-                                window=window)
+    from repro.kernels.flash.decode_attention import (decode_attention,
+                                                      decode_attention_ref)
 
-    def oracle():
-        from repro.kernels.flash.decode_attention import decode_attention_ref
-        return decode_attention_ref(q, k_cache, v_cache, positions, current,
-                                    window=window)
-
-    return route_and_run(disp, "decode_attention", q.dtype, native, oracle)
+    return route_and_run(
+        disp, "decode_attention", q.dtype,
+        functools.partial(decode_attention, window=window),
+        functools.partial(decode_attention_ref, window=window),
+        q, k_cache, v_cache, positions, current)
 
 
 # ---------------------------------------------------------------------------
@@ -392,26 +424,21 @@ def conv2d(x: jnp.ndarray, w: jnp.ndarray,
 
     from repro.kernels.conv import ops as conv_ops
 
-    if act is not None and epilogue_fusion_active():
-        return route_and_run(
-            disp, "conv2d", x.dtype,
-            lambda: conv_ops.conv2d(x, w, bias, stride=stride,
-                                    padding=padding, epilogue=act),
-            lambda: conv2d_ref(x, w, bias, stride=stride, padding=padding,
-                               epilogue=act))
+    fused = act if epilogue_fusion_active() else None
     out = route_and_run(
         disp, "conv2d", x.dtype,
-        lambda: conv_ops.conv2d(x, w, bias, stride=stride, padding=padding),
-        lambda: conv2d_ref(x, w, bias, stride=stride, padding=padding))
-    if act is None:
+        lambda x, w, b: conv_ops.conv2d(x, w, b, stride=stride,
+                                        padding=padding, epilogue=fused),
+        lambda x, w, b: conv2d_ref(x, w, b, stride=stride, padding=padding,
+                                   epilogue=fused), x, w, bias)
+    if act is None or fused is not None:
         return out
 
     from repro.kernels.act_lut.ops import lut_activation, lut_apply_ref
 
     return route_and_run(
-        disp, "act_lut", out.dtype,
-        lambda: lut_activation(act)(out),
-        lambda: lut_apply_ref(out, act))
+        disp, "act_lut", out.dtype, lut_activation(act),
+        lambda y: lut_apply_ref(y, act), out)
 
 
 def _pool(x: jnp.ndarray, *, window, stride, padding, kind: str):
@@ -426,8 +453,10 @@ def _pool(x: jnp.ndarray, *, window, stride, padding, kind: str):
         return oracle(x, window=window, stride=stride, padding=padding)
     return route_and_run(
         disp, kind, x.dtype,
-        lambda: native(x, window=window, stride=stride, padding=padding),
-        lambda: oracle(x, window=window, stride=stride, padding=padding))
+        functools.partial(native, window=window, stride=stride,
+                          padding=padding),
+        functools.partial(oracle, window=window, stride=stride,
+                          padding=padding), x)
 
 
 def avg_pool(x: jnp.ndarray, *, window: tuple[int, int],

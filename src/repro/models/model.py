@@ -54,9 +54,13 @@ class Model:
         return _DTYPES[self.cfg.dtype]
 
     def _dispatch_scope(self):
-        if self.dispatcher is None:
-            return contextlib.nullcontext()
-        return dsp.use_dispatcher(self.dispatcher)
+        """The dispatcher, and on a multi-device mesh the manual region
+        every Pallas kernel needs (`dsp.use_mesh`)."""
+        scope = contextlib.ExitStack()
+        scope.enter_context(dsp.use_dispatcher(self.dispatcher))
+        if self.ctx.active:
+            scope.enter_context(dsp.use_mesh(self.ctx.mesh))
+        return scope
 
     def init(self, key) -> Params:
         cfg = self.cfg

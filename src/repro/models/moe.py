@@ -53,8 +53,12 @@ def init_moe(key, cfg: ModelConfig, dtype) -> Params:
 
 
 def _route(cfg: ModelConfig, router_w: jnp.ndarray, x: jnp.ndarray):
-    """x: (T, d) -> (gates (T,k), idx (T,k), aux_loss). Router math in fp32."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w)
+    """x: (T, d) -> (gates (T,k), idx (T,k), aux_loss). Router math in fp32:
+    at default precision a TPU runs an fp32 dot as one bf16 pass, whose
+    result may depend on the token count, so EP ranks and the dense path
+    could route one token differently."""
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w,
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
@@ -116,8 +120,11 @@ def moe_dense(cfg: ModelConfig, p: Params, x: jnp.ndarray,
     ye = _expert_ffn(cfg, p["wg"], p["wu"], p["wd"], xe)          # (E, T, d)
     if ctx is not None and ctx.active:
         ye = ctx.constrain(ye, "model", None, None)
-    onehot = jax.nn.one_hot(idx, cfg.n_experts, dtype=ye.dtype)   # (T, k, E)
-    comb = jnp.einsum("tke,etd,tk->td", onehot, ye, gates.astype(ye.dtype))
+    # combine as the EP path does (`_ep_block` step 8): each token's k
+    # expert rows, then one gate-weighted contraction over k — the same
+    # summation order, so EP and dense agree bit for bit when nothing drops
+    yk = ye[idx, jnp.arange(xt.shape[0])[:, None]]                # (T, k, d)
+    comb = jnp.einsum("tkd,tk->td", yk, gates.astype(ye.dtype))
     out = comb.reshape(b, s, d)
     if "shared" in p:
         out = out + apply_mlp(cfg, p["shared"], x).reshape(b, s, d)
@@ -227,6 +234,10 @@ def moe_ep(cfg: ModelConfig, p: Params, x: jnp.ndarray, ctx: ParallelContext):
     seq_out = cfg.seq_shard and s % msize == 0
 
     def body(x_blk, router_w, wg, wu, wd):
+        with dsp.use_mesh(None):       # already manual over every axis
+            return _body(x_blk, router_w, wg, wu, wd)
+
+    def _body(x_blk, router_w, wg, wu, wd):
         b_loc, s_full, dd = x_blk.shape
         m = jax.lax.axis_index("model")
         if seq_out:

@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
+from repro.launch.mesh import make_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class RescalePlan:
@@ -63,14 +65,14 @@ def build_mesh(plan: RescalePlan, devices=None) -> Mesh:
     set, but in single-process simulation still contains the "failed"
     rows, so the elastic supervisor passes the survivors explicitly."""
     if devices is None:
-        return jax.make_mesh(plan.new_mesh_shape, plan.new_axis_names)
+        return make_mesh(plan.new_mesh_shape, plan.new_axis_names)
     devices = np.asarray(devices).reshape(-1)
     need = int(np.prod(plan.new_mesh_shape))
     if devices.size < need:
         raise ValueError(f"plan wants {need} devices, "
                          f"got {devices.size} survivors")
-    return Mesh(devices[:need].reshape(plan.new_mesh_shape),
-                plan.new_axis_names)
+    return make_mesh(plan.new_mesh_shape, plan.new_axis_names,
+                     devices=devices[:need])
 
 
 def make_placer(mesh: Mesh, spec_fn):

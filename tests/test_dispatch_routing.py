@@ -202,6 +202,16 @@ class TestDecodeResidency:
                 for _ in range(3)}
         assert len(keys) == 1
 
+    def test_content_hash_raises_on_untraceable(self):
+        """A program that cannot be traced has no content to key on: the
+        hash raises instead of keying by function name, which would let two
+        different programs share one ProgramCache entry."""
+        def branchy(x):
+            return x + 1 if x.sum() > 0 else x - 1   # concrete bool of a tracer
+
+        with pytest.raises(jax.errors.TracerBoolConversionError):
+            dispatch.content_hash(branchy, (jnp.ones(3),))
+
 
 # ---------------------------------------------------------------------------
 # Weight-form tags across the checkpoint boundary

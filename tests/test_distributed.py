@@ -27,6 +27,7 @@ def run8(body: str, timeout=600) -> str:
 @pytest.mark.slow
 def test_moe_ep_matches_dense_reference():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh
         from repro import configs
@@ -34,7 +35,7 @@ def test_moe_ep_matches_dense_reference():
         from repro.parallel.ctx import ParallelContext
         import dataclasses
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelContext(mesh=mesh)
         cfg = dataclasses.replace(configs.get_smoke("dbrx-132b"),
                                   moe_capacity_factor=8.0)   # no drops
@@ -58,11 +59,12 @@ def test_moe_ep_matches_dense_reference():
 @pytest.mark.slow
 def test_moe_ep_capacity_drops_are_bounded():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np, dataclasses
         from repro import configs
         from repro.models import moe as moe_lib
         from repro.parallel.ctx import ParallelContext
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelContext(mesh=mesh)
         cfg = dataclasses.replace(configs.get_smoke("dbrx-132b"),
                                   moe_capacity_factor=1.0)
@@ -82,6 +84,7 @@ def test_moe_ep_capacity_drops_are_bounded():
 @pytest.mark.slow
 def test_sharded_train_step_runs_and_matches_single_device():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro import configs
         from repro.models.model import build_model
@@ -113,7 +116,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
             p2, o2, metrics = fn(params, opt, batch)
             return float(metrics["loss"]), p2
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         loss_sharded, p_sh = run(ParallelContext(mesh=mesh))
         loss_single, p_si = run(ParallelContext(mesh=None))
         assert abs(loss_sharded - loss_single) < 2e-2, (loss_sharded, loss_single)
@@ -129,9 +132,10 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
 def test_gpipe_matches_sequential():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.pipeline import gpipe, split_stages, bubble_fraction
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = make_mesh((4,), ("stage",))
         L, S, M, mb, d = 8, 4, 6, 2, 16
         key = jax.random.PRNGKey(0)
         Ws = jax.random.normal(key, (L, d, d)) * 0.3
@@ -162,11 +166,12 @@ def test_gpipe_matches_sequential():
 
 def test_compressed_psum_close_to_exact():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 1024)),
                         jnp.float32)
         exact = shard_map(lambda v: jax.lax.psum(v, "data"), mesh=mesh,
@@ -182,6 +187,7 @@ def test_compressed_psum_close_to_exact():
 
 def test_elastic_restore_onto_new_mesh():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np, tempfile
         from repro.checkpoint.checkpoint import CheckpointManager
         from repro.runtime.elastic import plan_rescale, build_mesh, make_placer
@@ -193,7 +199,7 @@ def test_elastic_restore_onto_new_mesh():
             mgr.save(1, tree)
             plan = plan_rescale(8, 4, model_parallel=2)
             assert plan.new_mesh_shape == (2, 2)
-            mesh = jax.make_mesh((2, 2), ("data", "model"),
+            mesh = make_mesh((2, 2), ("data", "model"),
                                  devices=jax.devices()[:4])
             placer = make_placer(mesh, lambda path, shape: P(None, "model"))
             restored, step = mgr.restore(tree, placer=placer)
@@ -208,10 +214,11 @@ def test_elastic_restore_onto_new_mesh():
 
 def test_ring_attention_matches_reference():
     out = run8("""
+        from repro.launch.mesh import make_mesh
         import jax, jax.numpy as jnp, numpy as np
         from repro.parallel.ring_attention import ring_attention
         from repro.models.attention import chunked_attention
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         rng = np.random.default_rng(0)
         b, s, h, kvh, d = 2, 64, 4, 2, 32
         q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)

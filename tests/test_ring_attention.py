@@ -28,6 +28,7 @@ import pytest
 from repro import configs
 from repro.core import hal
 from repro.core.dispatch import ExecutionStream, ProgramCache
+from repro.launch.mesh import make_mesh
 from repro.launch.scheduler import Request, ServeConfig, build_scheduler
 from repro.models.attention import chunked_attention
 from repro.models.model import build_model
@@ -43,7 +44,7 @@ _multi = pytest.mark.skipif(
 
 @functools.lru_cache(maxsize=None)
 def _ring_ctx(min_tokens=1):
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     return dataclasses.replace(ParallelContext(mesh=mesh),
                                ring_prefill_min=min_tokens)
 
@@ -133,7 +134,7 @@ def test_ring_off_by_default_on_mesh():
     """Without opting in, a mesh context keeps ring routing OFF —
     `ring_prefill_min` defaults to None, protecting the bit-parity
     guarantee mesh serving CI gates."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh)
     assert ctx.ring_prefill_min is None
     single = _serve_tokens(ParallelContext(mesh=None))
