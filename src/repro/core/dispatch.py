@@ -37,6 +37,7 @@ from typing import Any, Callable, Hashable
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import hal
 
@@ -176,6 +177,11 @@ class ExecutionStream:
     floor estimate of its target (`Target.dispatch_floor_s`), so
     `work_s = max(0, wall - floor)` splits every dispatch into fixed overhead
     and useful work — the split the batching scheduler amortizes (§9.4).
+
+    Inside a profiler session each dispatch writes two host spans on the
+    profiler's clock: ``stream.launch`` (argument handling and the launch)
+    and ``stream.wait`` (blocking until the outputs are ready). Outside one
+    they record nothing.
     """
 
     def __init__(self, cache: ProgramCache | None = None, *,
@@ -212,8 +218,10 @@ class ExecutionStream:
         outs = []
         for compiled, args, kwargs, key, batch, depth, span in self._encoded:
             t0 = time.perf_counter()
-            out = compiled(*args, **kwargs)
-            out = jax.block_until_ready(out)
+            with TraceAnnotation("stream.launch"):
+                out = compiled(*args, **kwargs)
+            with TraceAnnotation("stream.wait"):
+                out = jax.block_until_ready(out)
             t1 = time.perf_counter()
             wall = t1 - t0
             self.records.append(DispatchRecord(
@@ -321,7 +329,10 @@ class AsyncExecutionStream(ExecutionStream):
     Outputs returned by `submit` are live JAX arrays (async futures): they
     can be fed straight into the next encoded op to chain device work
     without a host round-trip. `sync` is the barrier; `execute_sync`
-    degenerates to submit-then-sync so the base contract holds.
+    degenerates to submit-then-sync so the base contract holds. The
+    profiler spans are the base stream's: ``stream.launch`` around each
+    submitted call, ``stream.wait`` around the barrier on the calling thread
+    (the drain thread writes none).
     """
 
     def __init__(self, cache: ProgramCache | None = None, *,
@@ -375,7 +386,8 @@ class AsyncExecutionStream(ExecutionStream):
             with self._lock:
                 depth_now = len(self._pending)
             t_sub = time.perf_counter()
-            out = compiled(*args, **kwargs)     # async dispatch: returns now
+            with TraceAnnotation("stream.launch"):
+                out = compiled(*args, **kwargs)     # async: returns now
             rec = DispatchRecord(
                 key, 0.0, 0.0, self.floor_s, depth, batch, self._seq,
                 submit_ts=t_sub, inflight_depth=depth_now, span=span)
@@ -394,8 +406,9 @@ class AsyncExecutionStream(ExecutionStream):
         submission order; re-raises any execution error the drain saw."""
         with self._lock:
             handles = list(self._pending)
-        for h in handles:
-            h.done.wait()
+        with TraceAnnotation("stream.wait"):
+            for h in handles:
+                h.done.wait()
         with self._lock:
             errors, self._errors = list(self._errors), []
         if errors:

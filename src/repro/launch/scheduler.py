@@ -59,6 +59,7 @@ from typing import Any, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from repro.configs.base import ShapeConfig
@@ -312,8 +313,34 @@ class _Slot:
             and self.next_pos >= self.req.prompt.size
 
 
+@dataclasses.dataclass
+class ServeCounters:
+    """Work counts of a scheduler, raised where the work happens and
+    reported by `stats()`. Each decode dispatch adds one tick and splits its
+    `n_slots` lanes into lane-steps: generated (the output is sampled),
+    catch-up (a prompt token past the prefill bucket is teacher-forced) and
+    empty (a masked dummy lane). Difference two snapshots
+    (`dataclasses.asdict`) to count a window of a run."""
+
+    decode_ticks: int = 0
+    lane_steps_generated: int = 0
+    lane_steps_catchup: int = 0
+    lane_steps_empty: int = 0
+    admissions: int = 0
+    prefill_tokens: int = 0
+
+
 class _SchedulerBase:
-    """Shared machinery: bucketed prefill programs, admission, floor stats."""
+    """Shared machinery: bucketed prefill programs, admission, floor stats.
+
+    Inside a profiler session the serve loops write host spans on the
+    profiler's clock, beside the stream's ``stream.launch``/``stream.wait``:
+    ``serve.tick`` (one loop iteration), ``serve.hook`` (the `step_hook`
+    call), ``serve.admit`` (one admission or prefill chunk, with ``rid`` and
+    ``bucket``), ``serve.frames`` (token/position frames to the device),
+    ``serve.fetch`` (decode outputs to the host) and ``serve.advance`` (the
+    lanes' host state, sampling included). Outside one they record nothing.
+    """
 
     def __init__(self, model, params, cfg, *, max_len: int,
                  buckets: tuple[int, ...] | None = None,
@@ -330,6 +357,7 @@ class _SchedulerBase:
         self.stream = stream or ExecutionStream(program_cache, target=target)
         self.cache = program_cache or self.stream.cache
         self.sampler = TokenSampler(sampling, cfg.vocab, seed)
+        self.counters = ServeCounters()
         # Mesh serving: lanes span hosts over the batch axes, params
         # replicate except EP expert banks (serve_param_specs) — the
         # placement that keeps every token stream bit-identical to the
@@ -448,6 +476,7 @@ class _SchedulerBase:
             "dispatch_wall_s": sum(r.wall_s for r in recs),
             "per_request_dispatch_overhead_s": self.stream.total_floor_s() / n,
             "per_request_dispatches": len(recs) / n,
+            "counters": dataclasses.asdict(self.counters),
         }
         if self.ctx.active:
             # SPMD: every host issues the same command sequence, so each
@@ -482,6 +511,8 @@ class SequentialSchedule(_SchedulerBase):
                                           (r.arrival, r.rid))):
             self._check(req)
             L = req.prompt.size
+            self.counters.admissions += 1
+            self.counters.prefill_tokens += L
             batch = self._prefill_batch(req.prompt[None], req.frames)
             prefill, pkey = self._prefill_program(batch)
             self.stream.encode_operation(prefill, (self.params, batch),
@@ -503,6 +534,8 @@ class SequentialSchedule(_SchedulerBase):
                 self.stream.encode_operation(
                     decode, (self.params, caches, tokj, posj), dkey, batch=1)
                 caches, logits = self.stream.execute_sync()[0]
+                self.counters.decode_ticks += 1
+                self.counters.lane_steps_generated += 1
                 tok = self.sampler(np.asarray(logits)[0, -1], req.rid, pos + 1)
                 generated.append(tok)
             results.append(RequestResult(
@@ -717,18 +750,20 @@ class ContinuousSchedule(_SchedulerBase):
         bench audit). The final chunk hands off to `_finish_chunked`."""
         slot = self.slots[slot_idx]
         pend, req = slot.pending, slot.req
-        c0 = pend.done
-        n = min(self.prefill_chunk, pend.target - c0)
-        tokj = jnp.asarray(req.prompt[None, c0:c0 + n], jnp.int32)
-        pos0 = jnp.full((1,), c0, jnp.int32)
-        compiled, ckey = self._chunk_program(pend.staging, tokj, pos0)
-        self.stream.encode_operation(
-            compiled, (self.params, pend.staging, tokj, pos0), ckey,
-            batch=1, span=(c0, c0 + n))
-        pend.staging, _ = self.stream.execute_sync()[0]
-        pend.done = c0 + n
-        if pend.done >= pend.target:
-            self._finish_chunked(slot_idx)
+        with TraceAnnotation("serve.admit", rid=req.rid, bucket=pend.target):
+            c0 = pend.done
+            n = min(self.prefill_chunk, pend.target - c0)
+            self.counters.prefill_tokens += n
+            tokj = jnp.asarray(req.prompt[None, c0:c0 + n], jnp.int32)
+            pos0 = jnp.full((1,), c0, jnp.int32)
+            compiled, ckey = self._chunk_program(pend.staging, tokj, pos0)
+            self.stream.encode_operation(
+                compiled, (self.params, pend.staging, tokj, pos0), ckey,
+                batch=1, span=(c0, c0 + n))
+            pend.staging, _ = self.stream.execute_sync()[0]
+            pend.done = c0 + n
+            if pend.done >= pend.target:
+                self._finish_chunked(slot_idx)
 
     def _finish_chunked(self, slot_idx: int) -> None:
         """Admit the fully-staged prefix into the lane: the staging cache's
@@ -767,39 +802,43 @@ class ContinuousSchedule(_SchedulerBase):
             bucket = C * ((L - 1) // C)
         else:
             bucket = bucket_for(L, self.buckets)
-        sidx = jnp.asarray(slot_idx, jnp.int32)
-        # lane writes dispatch on the stream too: the floor ledger must
-        # charge every real dispatch, admissions included
-        if self._prefix_hit_admit(req, slot, sidx, bucket):
-            pass                  # admitted from resident blocks
-        elif C is not None and bucket > 0:
-            self._begin_chunked(slot, req, bucket)
-        elif bucket == 0:
-            self.stream.encode_operation(_reset_slot, (self.caches, sidx),
-                                         "reset_slot", batch=1)
-            self.caches = self.stream.execute_sync()[0]
-            if self.pool is not None:
-                self.pool.stats["misses"] += 1
-            slot.next_pos, slot.next_tok = 0, int(req.prompt[0])
-        else:
-            batch = self._prefill_batch(req.prompt[None, :bucket], req.frames)
-            prefill, pkey = self._prefill_program(batch)
-            self.stream.encode_operation(prefill, (self.params, batch),
-                                         pkey, batch=1)
-            pf_caches, logits = self.stream.execute_sync()[0]
-            if self.pool is not None:
-                self._pool_cold_insert(req, bucket, pf_caches)
-            self.stream.encode_operation(
-                _admit_into_slot, (self.caches, pf_caches, sidx),
-                "admit_slot", batch=1)
-            self.caches = self.stream.execute_sync()[0]
-            slot.next_pos = bucket
-            if bucket < L:        # catch up through decode, teacher-forced
-                slot.next_tok = int(req.prompt[bucket])
-            else:                 # prompt fully prefilled: sample token L
-                tok = self.sampler(np.asarray(logits)[0, -1], req.rid, L)
-                slot.generated.append(tok)
-                slot.next_tok = tok
+        self.counters.admissions += 1
+        with TraceAnnotation("serve.admit", rid=req.rid, bucket=bucket):
+            sidx = jnp.asarray(slot_idx, jnp.int32)
+            # lane writes dispatch on the stream too: the floor ledger must
+            # charge every real dispatch, admissions included
+            if self._prefix_hit_admit(req, slot, sidx, bucket):
+                pass                  # admitted from resident blocks
+            elif C is not None and bucket > 0:
+                self._begin_chunked(slot, req, bucket)
+            elif bucket == 0:
+                self.stream.encode_operation(_reset_slot, (self.caches, sidx),
+                                             "reset_slot", batch=1)
+                self.caches = self.stream.execute_sync()[0]
+                if self.pool is not None:
+                    self.pool.stats["misses"] += 1
+                slot.next_pos, slot.next_tok = 0, int(req.prompt[0])
+            else:
+                self.counters.prefill_tokens += bucket
+                batch = self._prefill_batch(req.prompt[None, :bucket],
+                                            req.frames)
+                prefill, pkey = self._prefill_program(batch)
+                self.stream.encode_operation(prefill, (self.params, batch),
+                                             pkey, batch=1)
+                pf_caches, logits = self.stream.execute_sync()[0]
+                if self.pool is not None:
+                    self._pool_cold_insert(req, bucket, pf_caches)
+                self.stream.encode_operation(
+                    _admit_into_slot, (self.caches, pf_caches, sidx),
+                    "admit_slot", batch=1)
+                self.caches = self.stream.execute_sync()[0]
+                slot.next_pos = bucket
+                if bucket < L:        # catch up through decode, teacher-forced
+                    slot.next_tok = int(req.prompt[bucket])
+                else:                 # prompt fully prefilled: sample token L
+                    tok = self.sampler(np.asarray(logits)[0, -1], req.rid, L)
+                    slot.generated.append(tok)
+                    slot.next_tok = tok
         slot.req = req
         slot.bucket = bucket
         slot.admitted_step = step
@@ -812,8 +851,10 @@ class ContinuousSchedule(_SchedulerBase):
         slot.next_pos = pos_written + 1
         nxt = pos_written + 1
         if nxt < req.prompt.size:            # still catching up: teacher-force
+            self.counters.lane_steps_catchup += 1
             slot.next_tok = int(req.prompt[nxt])
             return
+        self.counters.lane_steps_generated += 1
         tok = self.sampler(logits_row, req.rid, nxt)
         slot.generated.append(tok)
         slot.next_tok = tok
@@ -839,53 +880,61 @@ class ContinuousSchedule(_SchedulerBase):
         self._queue, self._results = queue, results
         step = 0
         while queue or any(s.active for s in self.slots):
-            if self.step_hook is not None:
-                self.step_hook(self, step)
-            # admissions: free lanes x arrived requests, in arrival order
-            for i, slot in enumerate(self.slots):
-                if not queue or queue[0].arrival > step:
+            with TraceAnnotation("serve.tick"):
+                if self.step_hook is not None:
+                    with TraceAnnotation("serve.hook"):
+                        self.step_hook(self, step)
+                # admissions: free lanes x arrived requests, in arrival order
+                for i, slot in enumerate(self.slots):
+                    if not queue or queue[0].arrival > step:
+                        break
+                    if not slot.active:
+                        self._admit(i, queue.pop(0), step)
+                # pending lanes advance ONE chunk per tick, so a decode window
+                # runs between every pair of chunks instead of the whole prompt
+                # blocking the in-flight lanes at once
+                for i, slot in enumerate(self.slots):
+                    if slot.prefilling:
+                        self._advance_chunk(i, step)
+                active = [s for s in self.slots
+                          if s.active and not s.prefilling
+                          and not (s.generating and len(s.generated)
+                                   >= s.req.max_new_tokens)]
+                # a fully-prefilled request can finish without a decode step
+                for s in list(self.slots):
+                    if s.active and s.generating \
+                            and len(s.generated) >= s.req.max_new_tokens:
+                        self._advance_finished(s, results, step)
+                if not active:
+                    if queue or any(s.prefilling for s in self.slots):
+                        step += 1     # idle tick: arrival or mid-chunk prefill
+                        continue
                     break
-                if not slot.active:
-                    self._admit(i, queue.pop(0), step)
-            # pending lanes advance ONE chunk per tick, so a decode window
-            # runs between every pair of chunks instead of the whole prompt
-            # blocking the in-flight lanes at once
-            for i, slot in enumerate(self.slots):
-                if slot.prefilling:
-                    self._advance_chunk(i, step)
-            active = [s for s in self.slots
-                      if s.active and not s.prefilling
-                      and not (s.generating
-                               and len(s.generated) >= s.req.max_new_tokens)]
-            # a fully-prefilled request can finish without a decode step
-            for s in list(self.slots):
-                if s.active and s.generating \
-                        and len(s.generated) >= s.req.max_new_tokens:
-                    self._advance_finished(s, results, step)
-            if not active:
-                if queue or any(s.prefilling for s in self.slots):
-                    step += 1     # idle tick: arrival or mid-chunk prefill
-                    continue
-                break
-            # one slot-masked decode dispatch for every lane
-            tok = np.zeros((self.n_slots, 1), np.int32)
-            pos = np.zeros((self.n_slots,), np.int32)
-            for i, s in enumerate(self.slots):
-                if s.active and not s.prefilling:
-                    tok[i, 0] = s.next_tok
-                    pos[i] = s.next_pos
-            tokj = self._batch_put(tok)
-            posj = self._batch_put(pos)
-            decode, dkey = self._decode_program(self.caches, tokj, posj)
-            self.stream.encode_operation(
-                decode, (self.params, self.caches, tokj, posj), dkey,
-                batch=len(active))
-            self.caches, logits = self.stream.execute_sync()[0]
-            lg = np.asarray(logits[:, -1, : self.cfg.vocab], np.float32)
-            for i, s in enumerate(self.slots):
-                if s.active and not s.prefilling:
-                    self._advance(s, lg[i], results, step)
-            step += 1
+                # one slot-masked decode dispatch for every lane
+                with TraceAnnotation("serve.frames"):
+                    tok = np.zeros((self.n_slots, 1), np.int32)
+                    pos = np.zeros((self.n_slots,), np.int32)
+                    for i, s in enumerate(self.slots):
+                        if s.active and not s.prefilling:
+                            tok[i, 0] = s.next_tok
+                            pos[i] = s.next_pos
+                    tokj = self._batch_put(tok)
+                    posj = self._batch_put(pos)
+                decode, dkey = self._decode_program(self.caches, tokj, posj)
+                self.stream.encode_operation(
+                    decode, (self.params, self.caches, tokj, posj), dkey,
+                    batch=len(active))
+                self.caches, logits = self.stream.execute_sync()[0]
+                self.counters.decode_ticks += 1
+                self.counters.lane_steps_empty += self.n_slots - len(active)
+                with TraceAnnotation("serve.fetch"):
+                    lg = np.asarray(logits[:, -1, : self.cfg.vocab],
+                                    np.float32)
+                with TraceAnnotation("serve.advance"):
+                    for i, s in enumerate(self.slots):
+                        if s.active and not s.prefilling:
+                            self._advance(s, lg[i], results, step)
+                step += 1
         results.sort(key=lambda r: r.rid)
         return results
 
@@ -1083,36 +1132,39 @@ class SLOSchedule(ContinuousSchedule):
         the materialized tokens back into the host state machines."""
         k = self._window_horizon(step, queue)
         n = self.n_slots
-        tok0 = np.zeros((n, 1), np.int32)
-        rids = np.zeros((n,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s.active and not s.prefilling:
-                tok0[i, 0] = s.next_tok
-                rids[i] = s.req.rid
-        tok_dev = self._batch_put(tok0)   # becomes a chained async value
-        ridsj = self._batch_put(rids)
+        c = self.counters
+        with TraceAnnotation("serve.frames"):
+            tok0 = np.zeros((n, 1), np.int32)
+            rids = np.zeros((n,), np.int32)
+            for i, s in enumerate(self.slots):
+                if s.active and not s.prefilling:
+                    tok0[i, 0] = s.next_tok
+                    rids[i] = s.req.rid
+            tok_dev = self._batch_put(tok0)   # becomes a chained async value
+            ridsj = self._batch_put(rids)
         plan: list[tuple[Any, list[int]]] = []
         for _ in range(k):
-            pos = np.zeros((n,), np.int32)
-            forced = np.zeros((n,), np.int32)
-            mask = np.zeros((n,), bool)
-            sampled_lanes: list[int] = []
-            n_active = 0
-            for i, s in enumerate(self.slots):
-                if not s.active or s.prefilling:
-                    continue
-                n_active += 1
-                pos[i] = s.next_pos
-                nxt = s.next_pos + 1
-                if nxt < s.req.prompt.size:   # catch-up: teacher-force
-                    forced[i] = int(s.req.prompt[nxt])
-                else:
-                    mask[i] = True
-                    sampled_lanes.append(i)
-                s.next_pos = nxt
-            posj = self._batch_put(pos)
-            forcedj = self._batch_put(forced)
-            maskj = self._batch_put(mask)
+            with TraceAnnotation("serve.frames"):
+                pos = np.zeros((n,), np.int32)
+                forced = np.zeros((n,), np.int32)
+                mask = np.zeros((n,), bool)
+                sampled_lanes: list[int] = []
+                n_active = 0
+                for i, s in enumerate(self.slots):
+                    if not s.active or s.prefilling:
+                        continue
+                    n_active += 1
+                    pos[i] = s.next_pos
+                    nxt = s.next_pos + 1
+                    if nxt < s.req.prompt.size:   # catch-up: teacher-force
+                        forced[i] = int(s.req.prompt[nxt])
+                    else:
+                        mask[i] = True
+                        sampled_lanes.append(i)
+                    s.next_pos = nxt
+                posj = self._batch_put(pos)
+                forcedj = self._batch_put(forced)
+                maskj = self._batch_put(mask)
             compiled, dkey = self._fused_step_program(
                 self.caches, tok_dev, posj, forcedj, maskj, ridsj)
             self.stream.encode_operation(
@@ -1122,19 +1174,26 @@ class SLOSchedule(ContinuousSchedule):
             # async values; the background drain confirms completions
             self.caches, tok_dev, samp = self.stream.submit()[0]
             plan.append((samp, sampled_lanes))
+            c.decode_ticks += 1
+            c.lane_steps_generated += len(sampled_lanes)
+            c.lane_steps_catchup += n_active - len(sampled_lanes)
+            c.lane_steps_empty += n - n_active
         self.stream.sync()
         self._observe_decode_walls()
-        nxt_host = np.asarray(tok_dev)[:, 0]
-        for t, (samp, sampled_lanes) in enumerate(plan):
-            samp_np = np.asarray(samp) if sampled_lanes else None
-            for i in sampled_lanes:
-                s = self.slots[i]
-                s.generated.append(int(samp_np[i]))
-                if len(s.generated) >= s.req.max_new_tokens:
-                    self._advance_finished(s, results, step + t)
-        for i, s in enumerate(self.slots):
-            if s.active and not s.prefilling:
-                s.next_tok = int(nxt_host[i])
+        with TraceAnnotation("serve.fetch"):
+            nxt_host = np.asarray(tok_dev)[:, 0]
+            fetched = [(np.asarray(samp) if lanes else None, lanes)
+                       for samp, lanes in plan]
+        with TraceAnnotation("serve.advance"):
+            for t, (samp_np, sampled_lanes) in enumerate(fetched):
+                for i in sampled_lanes:
+                    s = self.slots[i]
+                    s.generated.append(int(samp_np[i]))
+                    if len(s.generated) >= s.req.max_new_tokens:
+                        self._advance_finished(s, results, step + t)
+            for i, s in enumerate(self.slots):
+                if s.active and not s.prefilling:
+                    s.next_tok = int(nxt_host[i])
         return step + k
 
     def run(self, requests: list[Request]) -> list[RequestResult]:
@@ -1146,39 +1205,41 @@ class SLOSchedule(ContinuousSchedule):
         self._queue, self._results = queue, results
         step = 0
         while queue or any(s.active for s in self.slots):
-            if self.step_hook is not None:
-                self.step_hook(self, step)
-            # admissions happen at a drained barrier (prefill + lane writes
-            # are stream dispatches themselves); the gate reads the ledger
-            for i, slot in enumerate(self.slots):
-                if not queue or queue[0].arrival > step:
+            with TraceAnnotation("serve.tick"):
+                if self.step_hook is not None:
+                    with TraceAnnotation("serve.hook"):
+                        self.step_hook(self, step)
+                # admissions happen at a drained barrier (prefill + lane writes
+                # are stream dispatches themselves); the gate reads the ledger
+                for i, slot in enumerate(self.slots):
+                    if not queue or queue[0].arrival > step:
+                        break
+                    if slot.active:
+                        continue
+                    if not self._admission_clear():
+                        self.deferred_admissions += 1
+                        break
+                    self._admit(i, queue.pop(0), step)
+                # pending lanes advance ONE chunk at this drained barrier, so
+                # the SLO gate and the in-flight decode window both see each
+                # chunk as an ordinary dispatch — never a monolithic stall
+                for i, slot in enumerate(self.slots):
+                    if slot.prefilling:
+                        self._advance_chunk(i, step)
+                # a fully-prefilled request can finish without a decode step
+                for s in list(self.slots):
+                    if s.active and s.generating \
+                            and len(s.generated) >= s.req.max_new_tokens:
+                        self._advance_finished(s, results, step)
+                if not any(s.active for s in self.slots):
+                    if queue:
+                        step += 1     # idle tick: wait for the next arrival
+                        continue
                     break
-                if slot.active:
+                if not any(s.active and not s.prefilling for s in self.slots):
+                    step += 1         # only mid-chunk lanes: nothing to decode
                     continue
-                if not self._admission_clear():
-                    self.deferred_admissions += 1
-                    break
-                self._admit(i, queue.pop(0), step)
-            # pending lanes advance ONE chunk at this drained barrier, so
-            # the SLO gate and the in-flight decode window both see each
-            # chunk as an ordinary dispatch — never a monolithic stall
-            for i, slot in enumerate(self.slots):
-                if slot.prefilling:
-                    self._advance_chunk(i, step)
-            # a fully-prefilled request can finish without a decode step
-            for s in list(self.slots):
-                if s.active and s.generating \
-                        and len(s.generated) >= s.req.max_new_tokens:
-                    self._advance_finished(s, results, step)
-            if not any(s.active for s in self.slots):
-                if queue:
-                    step += 1     # idle tick: wait for the next arrival
-                    continue
-                break
-            if not any(s.active and not s.prefilling for s in self.slots):
-                step += 1         # only mid-chunk lanes: nothing to decode
-                continue
-            step = self._pipelined_window(step, queue, results)
+                step = self._pipelined_window(step, queue, results)
         results.sort(key=lambda r: r.rid)
         return results
 

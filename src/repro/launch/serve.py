@@ -431,14 +431,21 @@ def run(argv=None) -> dict:
                     f"{stats['acceptance_rate']:.2f}, "
                     f"{stats['tokens_per_window_dispatch']:.2f} "
                     f"tok/window-dispatch")
+    c = stats["counters"]
+    work = c["lane_steps_generated"] + c["lane_steps_catchup"]
+    lane_note = ""
+    if work:
+        lane_note = (f" | {c['decode_ticks']} decode ticks, lane occupancy "
+                     f"{100 * work / (work + c['lane_steps_empty']):.1f}%, "
+                     f"catch-up {100 * c['lane_steps_catchup'] / work:.1f}%")
     print(f"{args.schedule} x {args.sampling}: {n_requests} requests "
           f"(lens {lens}) gen {args.gen}: {wall*1e3:.1f} ms "
           f"({serve_wall*1e3:.1f} ms ex-compile, {out['tok_per_s']:.1f} "
           f"tok/s) | {stats['n_dispatches']} "
-          f"dispatches, floor/request "
+          f"dispatches, modeled floor/request "
           f"{stats['per_request_dispatch_overhead_s']*1e6:.1f} us | "
           f"program cache h{program_cache.stats.hits}/"
-          f"m{program_cache.stats.misses}{mesh_note}{prefix_note}"
+          f"m{program_cache.stats.misses}{lane_note}{mesh_note}{prefix_note}"
           f"{chunk_note}{slo_note}")
     return out
 
