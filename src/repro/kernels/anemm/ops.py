@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.anemm.anemm import anemm as _anemm_kernel
+from repro.kernels.anemm.anemm import anemm_head as _head_kernel
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -35,6 +36,31 @@ def _bwd(ane_mode, res, g):
 
 
 matmul.defvjp(_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def head(a: jnp.ndarray, w: jnp.ndarray, layout: str):
+    """The logits head: `a` (M, K) against the weight as stored — (K, N)
+    for layout "kn", the tied (N, K) table for "nk" — fp32 out."""
+    return _head_kernel(a, w, layout=layout, out_dtype=jnp.float32)
+
+
+def _head_fwd(a, w, layout):
+    return head(a, w, layout), (a, w)
+
+
+def _head_bwd(layout, res, g):
+    a, w = res
+    g = g.astype(jnp.float32)
+    a32, w32 = a.astype(jnp.float32), w.astype(jnp.float32)
+    if layout == "nk":
+        da, dw = g @ w32, g.T @ a32
+    else:
+        da, dw = g @ w32.T, a32.T @ g
+    return da.astype(a.dtype), dw.astype(w.dtype)
+
+
+head.defvjp(_head_fwd, _head_bwd)
 
 
 def linear(a, b, scale=None, bias=None, *, ane_mode: bool = False,

@@ -26,3 +26,10 @@ def anemm_ref(a, b, scale=None, bias=None, *, ane_mode: bool = False,
         from repro.kernels.act_lut.ops import lut_apply_ref
         out = lut_apply_ref(out, epilogue)
     return out
+
+
+def anemm_head_ref(a, w, layout: str):
+    """The head's product as `anemm_head` computes it: operands as stored,
+    fp32 accumulate, fp32 out; `w` is (K, N) for "kn", (N, K) for "nk"."""
+    dims = (((1,), (1 if layout == "nk" else 0,)), ((), ()))
+    return jax.lax.dot_general(a, w, dims, preferred_element_type=jnp.float32)

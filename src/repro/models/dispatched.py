@@ -13,12 +13,14 @@ model stack:
     survives jit tracing, `lax.scan` stacking/slicing over layers, expert
     indexing, and checkpoint round trips (`checkpoint/` knows the node).
   * `linear(x, w)` — the single matmul entry point the layers call. Every
-    projection, MLP matrix, MoE expert bank, and logits head resolves here:
-    with a dispatcher in scope the call is routed through
+    projection, MLP matrix, MoE expert bank, and packed logits head
+    resolves here: with a dispatcher in scope the call is routed through
     `core.dispatch.KernelDispatcher` (`anemm` for dense, `palette`/`sparse`
     for packed forms) with oracle fallback when the configured HAL target
     gates the kernel; with no dispatcher and a plain dense weight it lowers
     to the exact `dot_general` the seed emitted (bit-stable default path).
+  * `head(x, w, layout=)` — the dense logits head on the same `anemm` row:
+    the weight read as stored (the tied table as (V, d)), fp32 out.
   * `flash_route` / `decode_route` — the attention matmuls, routed through
     the `flash` and `decode_attention` registry rows the same way.
 
@@ -303,6 +305,25 @@ def _matmul_dense(disp: KernelDispatcher, a2: jnp.ndarray,
         disp, "anemm", a2.dtype,
         lambda a, w: anemm_ops.matmul(a, w.astype(a.dtype)),
         lambda a, w: anemm_ref(a, w.astype(a.dtype)), a2, w2)
+
+
+def head(x: jnp.ndarray, w: jnp.ndarray, *, layout: str) -> jnp.ndarray:
+    """The routed logits head, fp32 out: `x` (..., K) against the dense
+    weight as stored — (K, N) for layout "kn", the tied embedding table
+    (N, K) for "nk" — with fp32 accumulation over operands in `x`'s dtype.
+    The route resolves the `anemm` row under fp32, the head's output
+    dtype, so the op-by-device matrix keys the head as before."""
+    from repro.kernels.anemm import ops as anemm_ops
+    from repro.kernels.anemm.ref import anemm_head_ref
+
+    k = x.shape[-1]
+    n = w.shape[0] if layout == "nk" else w.shape[1]
+    out2 = route_and_run(
+        active_dispatcher(), "anemm", jnp.float32,
+        lambda a, w: anemm_ops.head(a, w, layout),
+        lambda a, w: anemm_head_ref(a, w, layout),
+        x.reshape(-1, k), w.astype(x.dtype))
+    return out2.reshape(x.shape[:-1] + (n,))
 
 
 def _matmul_packed(disp: KernelDispatcher, a2: jnp.ndarray,

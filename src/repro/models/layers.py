@@ -150,19 +150,22 @@ def embed_tokens(p: Params, tokens: jnp.ndarray) -> jnp.ndarray:
 
 
 def logits(cfg: ModelConfig, p: Params, x: jnp.ndarray) -> jnp.ndarray:
-    """Always fp32 out: the cancellation-heavy step gets the wide anchor."""
+    """Always fp32 out: the cancellation-heavy step gets the wide anchor.
+    Operands stay in the activation dtype — a product of two bf16 values
+    is exact in fp32, so fp32 accumulation is the whole anchor."""
     if cfg.tie_embeddings:
-        w = p["table"].T
+        w, layout = p["table"], "nk"             # (V, d), read as stored
     else:
-        w = p["unembed"]
-    if isinstance(w, dsp.DispatchedWeight) or dsp.active_dispatcher() is not None:
-        # routed head: run the whole matmul in fp32 so the anchor holds even
-        # when the kernel stores in the activation dtype
+        w, layout = p["unembed"], "kn"           # (d, V)
+    if isinstance(w, dsp.DispatchedWeight):
+        # packed head: the palette/sparse kernels store in the activation
+        # dtype, so the fp32 anchor comes from fp32 activations
         return dsp.linear(x.astype(jnp.float32), w)
-    out = jax.lax.dot_general(
-        x, w.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return out  # fp32
+    if dsp.active_dispatcher() is not None:
+        return dsp.head(x, w, layout=layout)
+    dims = (((x.ndim - 1,), (1 if layout == "nk" else 0,)), ((), ()))
+    return jax.lax.dot_general(x, w.astype(x.dtype), dims,
+                               preferred_element_type=jnp.float32)
 
 
 def sinusoidal_positions(length: int, dim: int) -> jnp.ndarray:

@@ -82,6 +82,24 @@ def test_anemm_compiles(mosaic, one_chip, m, k, n, dtype):
     _compile(matmul, a, b)
 
 
+@pytest.mark.parametrize("layout", ["nk", "kn"], ids=["tied", "untied"])
+def test_anemm_head_compiles(mosaic, one_chip, layout):
+    """The logits head at 16 lanes: bf16 activations against the bf16 head
+    weight as stored — the tied (V, d) table or an untied (d, V) — fp32
+    out, within the VMEM limit its blocks ask for; no copy or cast of the
+    weight in the program."""
+    from repro.kernels.anemm.ops import head
+    a = jax.ShapeDtypeStruct((16, D_MODEL), BF16, sharding=one_chip)
+    shape = (VOCAB, D_MODEL) if layout == "nk" else (D_MODEL, VOCAB)
+    w = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    compiled = jax.jit(lambda a, w: head(a, w, layout)).lower(a, w).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "anemm_head" in text
+    assert f"f32[16,{VOCAB}]" in text
+    assert " copy(" not in text and " convert(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def test_flash_attention_prefill_compiles(mosaic, one_chip):
     from repro.kernels.flash.flash_attention import flash_attention
     q = jax.ShapeDtypeStruct((1, HEADS, SEQ, D_HEAD), BF16, sharding=one_chip)
