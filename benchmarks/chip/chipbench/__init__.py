@@ -1,6 +1,10 @@
 """The chip benchmark's harness (see `cli.py`)."""
 
 import importlib.util
+import os
+
+# the benchmark's directory: configs/, references/, traffic/, checks/, metrics/
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def load_module(path: str, name: str):

@@ -19,9 +19,8 @@ import os
 import sys
 import tempfile
 
-from chipbench import device, load_module, traffic
+from chipbench import BENCH_DIR, device, load_module, traffic
 
-BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(os.path.dirname(BENCH_DIR))
 
 
@@ -100,7 +99,7 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
     jax.monitoring.register_event_duration_secs_listener(compile_log)
     specs = traffic.generate(cell.mix, cell.conf["model"]["vocab"], seed,
                              seconds)
-    server = serving.Server(cell.conf, seed)
+    server = serving.Server(cell.conf, seed, cell.chips)
     warm = server.warm_up([len(s.prompt) for s in specs])
     log(f"warm-up prompt lengths {warm}")
     summary = None

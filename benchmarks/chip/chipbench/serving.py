@@ -28,7 +28,8 @@ import time
 
 import numpy as np
 
-from chipbench import weights
+from chipbench import BENCH_DIR, weights
+from chipbench.check import load_reference
 from chipbench.tracing import null_span
 
 # a tick number no run reaches: a request waits with it until it is due
@@ -104,43 +105,35 @@ class CompileLog:
         return sum(t0 <= t < t1 for t in self.stamps)
 
 
-def program_params(model, m: dict, seed: int):
-    """The benchmark's weights (`chipbench.weights`) in the program's own
-    parameter tree, made on the device in one jitted call. Every leaf of
-    the program's tree must be matched by name and size, or this fails."""
+def program_params(model, module, m: dict, seed: int):
+    """The benchmark's weights in the program's own parameter tree, made on
+    the device in one jitted call: the reference ``module``'s
+    ``program_leaf`` fills every leaf of the program's tree by its path,
+    in the leaf's shape and dtype, or this fails naming the leaves it
+    cannot fill."""
     import jax
     from repro.kernels import compat
 
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     flat, treedef = compat.tree_flatten_with_path(shapes)
-    names = [compat.tree_path_str(p) for p, _ in flat]
-    outer_names = {"embed/table": "embed", "final_ln/scale": "final_norm"}
-    layer_names = {"ln1/scale": "attn_norm", "ln2/scale": "mlp_norm",
-                   "mix/wq": "wq", "mix/wk": "wk", "mix/wv": "wv",
-                   "mix/wo": "wo", "mlp/wg": "wg", "mlp/wu": "wu",
-                   "mlp/wd": "wd"}
-
-    def canonical(name: str) -> tuple[str, str]:
-        if name in outer_names:
-            return "outer", outer_names[name]
-        if name.startswith("layers/0/sub0/"):
-            rest = name[len("layers/0/sub0/"):]
-            if rest in layer_names:
-                return "layer", layer_names[rest]
-        raise ValueError(f"program parameter {name!r} has no benchmark "
-                         f"weight: the dense-GQA layout changed")
-
-    picks = [canonical(n) for n in names]
+    paths = [compat.tree_path_str(p) for p, _ in flat]
 
     def build(key):
-        src = {"outer": weights.outer(key, m), "layer": weights.stacked(key, m)}
-        leaves = []
-        for (group, name), (_, spec) in zip(picks, flat):
-            w = src[group][name]
-            if w.size != int(np.prod(spec.shape)):
-                raise ValueError(f"{group}/{name}: {w.shape} does not fill "
-                                 f"{spec.shape}")
-            leaves.append(w.reshape(spec.shape).astype(spec.dtype))
+        leaves, unknown = [], []
+        for path, (_, spec) in zip(paths, flat):
+            try:
+                w = module.program_leaf(key, m, path, spec.shape)
+            except KeyError as e:
+                unknown.append(f"{path} (KeyError {e})")
+                continue
+            if w.shape != spec.shape or w.dtype != spec.dtype:
+                raise ValueError(f"{module.__name__} gives {path} as "
+                                 f"{w.dtype}{list(w.shape)}; the program "
+                                 f"holds {spec.dtype}{list(spec.shape)}")
+            leaves.append(w)
+        if unknown:
+            raise ValueError(f"{module.__name__} has no weight for program "
+                             f"parameter(s) {', '.join(unknown)}")
         return jax.tree.unflatten(treedef, leaves)
 
     # the key is an argument, not a constant: one program serves every seed
@@ -150,7 +143,10 @@ def program_params(model, m: dict, seed: int):
 class Server:
     """The program, built once per process: model, weights, programs."""
 
-    def __init__(self, conf: dict, seed: int) -> None:
+    def __init__(self, conf: dict, seed: int, chips: int) -> None:
+        if chips != 1:
+            raise ValueError(f"the cell asks for {chips} chips; the harness "
+                             f"builds no mesh and serves on one device")
         import jax
         from repro import configs
         from repro.core.dispatch import KernelDispatcher, ProgramCache
@@ -166,7 +162,8 @@ class Server:
         self.model = build_model(self.mcfg,
                                  dispatcher=KernelDispatcher(self.target))
         self.program_cache = ProgramCache()
-        self.params = program_params(self.model, m, seed)
+        self.params = program_params(
+            self.model, load_reference(BENCH_DIR, conf["reference"]), m, seed)
         jax.block_until_ready(self.params)
         self.seed = seed
         self.sched = None

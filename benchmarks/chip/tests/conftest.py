@@ -18,9 +18,11 @@ import json  # noqa: E402
 import pytest  # noqa: E402
 
 
-def tiny_cell(limit: float | None = None, vocab: int = 1024):
+def tiny_cell(limit: float | None = None, vocab: int = 1024,
+              arch: str = "phi4-mini-3.8b", tied: bool = True):
     """A phi4-shaped cell small enough for the CPU (kernels interpreted),
-    held to the phi4 chat cell's limit unless told otherwise."""
+    held to the phi4 chat cell's limit unless told otherwise; ``arch``
+    names the program's configuration the sizes are laid over."""
     from chipbench import cli
 
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -29,12 +31,12 @@ def tiny_cell(limit: float | None = None, vocab: int = 1024):
         limits = json.load(f)
     if limit is not None:
         limits = {"widest_gap": limit}
-    conf = {"name": "tiny", "program_arch": "phi4-mini-3.8b",
+    conf = {"name": "tiny", "program_arch": arch,
             "reference": "dense_gqa",
             "model": {"n_layers": 2, "d_model": 256, "n_heads": 4,
                       "n_kv_heads": 2, "d_head": 64, "d_ff": 512,
                       "vocab": vocab, "norm": "rmsnorm", "act": "silu",
-                      "tie_embeddings": True, "rope_theta": 10000.0,
+                      "tie_embeddings": tied, "rope_theta": 10000.0,
                       "dtype": "bfloat16"},
             "serving": {"n_slots": 4, "max_len": 64,
                         "schedule": "continuous", "sampling": "greedy"}}

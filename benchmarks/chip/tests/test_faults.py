@@ -1,11 +1,13 @@
 """A whole run on the CPU, the look for a chip skipped, with the timed path
 sound and then broken underneath: ``correct`` must follow."""
 
+import json
+import os
 import time
 
 import pytest
 
-from chipbench import cli
+from chipbench import BENCH_DIR, cli
 
 
 def run(cell, seed=2 ** 35 + 1, seconds=2.0):
@@ -52,3 +54,33 @@ def test_run_without_finished_requests_is_not_correct(tiny, monkeypatch):
     monkeypatch.setattr(check, "sample", lambda *a: [])
     got, out = run(tiny())
     assert out["correct"] is False
+
+
+def cell_limits():
+    with open(os.path.join(os.path.dirname(os.path.dirname(BENCH_DIR)),
+                           "BENCHMARK.json")) as f:
+        names = [w["name"] for w in json.load(f)["workloads"]]
+    out = []
+    for name in names:
+        with open(os.path.join(BENCH_DIR, "checks", f"{name}.json")) as f:
+            out.append(pytest.param(json.load(f)["widest_gap"], id=name))
+    return out
+
+
+@pytest.mark.parametrize("limit", cell_limits())
+def test_token_altered_fails_each_cell_limit(tiny, monkeypatch, limit):
+    """Under every cell's own limit, one served token in seven shifted
+    where the sampler produces it is not correct."""
+    from repro.launch import scheduler
+
+    real = scheduler.TokenSampler.__call__
+    vocab = tiny().conf["model"]["vocab"]
+
+    def altered(self, row, rid, position):
+        tok = real(self, row, rid, position)
+        return (tok + vocab // 2) % vocab if position % 7 == 0 else tok
+
+    monkeypatch.setattr(scheduler.TokenSampler, "__call__", altered)
+    got, out = run(tiny(limit=limit))
+    assert out["correct"] is False
+    assert out["checks"]["widest_gap"]["limit"] == limit

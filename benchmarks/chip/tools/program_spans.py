@@ -69,7 +69,7 @@ def measure(cell, seed: int, seconds: float, dev) -> dict:
     jax.monitoring.register_event_duration_secs_listener(compile_log)
     specs = traffic.generate(cell.mix, cell.conf["model"]["vocab"], seed,
                              seconds)
-    server = serving.Server(cell.conf, seed)
+    server = serving.Server(cell.conf, seed, cell.chips)
     server.warm_up([len(s.prompt) for s in specs])
     with tempfile.TemporaryDirectory(prefix="chipbench-trace-") as tmp:
         tracer = WindowTracer(tmp, server)

@@ -43,7 +43,7 @@ def main() -> int:
     cell = cli.load_cell(args.workload)
     cli.configure_jax()
     dev = device.require_tpu(cell.chips)
-    server = serving.Server(cell.conf, args.seed)
+    server = serving.Server(cell.conf, args.seed, cell.chips)
     runs = []
     for rate in [float(r) for r in args.rates.split(",")]:
         mix = dict(cell.mix, arrival=dict(cell.mix["arrival"],
